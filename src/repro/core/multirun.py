@@ -414,7 +414,11 @@ def _get_pool(workers: int):
     if _pool is None:
         # spawn, never fork: workers import a fresh interpreter so cell
         # results cannot depend on inherited parent state (and the same
-        # start method runs everywhere).
+        # start method runs everywhere).  Workers only run ``run_cell``,
+        # the numpy DES, which does not import JAX, so no worker asks for
+        # an accelerator that the parent (e.g. benchmarks/run.py after its
+        # kernel suite) holds.  Only a spec whose ``sched_kwargs`` pick
+        # ``placement_backend="jax"`` would load JAX here; no caller does.
         _pool = get_context("spawn").Pool(processes=workers)
         _pool_workers = workers
     return _pool

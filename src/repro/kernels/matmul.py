@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams
-
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref):
     k = pl.program_id(2)
@@ -53,7 +51,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -4,7 +4,7 @@ Each op dispatches to the Pallas kernel on TPU (or when
 ``REPRO_FORCE_PALLAS_INTERPRET=1`` forces the interpreter for validation)
 and to the pure-jnp reference (XLA) otherwise.  Model code and the task
 runtime call *these*, never the kernels directly, so the same program runs
-on this CPU-only container and on a real pod.
+on the XLA CPU backend (the tests) and on a TPU.
 """
 from __future__ import annotations
 

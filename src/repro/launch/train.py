@@ -16,6 +16,7 @@ from ..configs import ARCHS
 from ..data import DataConfig
 from ..optim import AdamWConfig
 from ..train.trainer import Trainer, TrainerConfig
+from .cache import use_compile_cache
 
 
 def main() -> None:
@@ -31,6 +32,7 @@ def main() -> None:
                     help="use the full (not reduced) architecture config")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = ARCHS[args.arch]
     if not args.full_config:
         cfg = cfg.reduced()

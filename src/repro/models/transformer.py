@@ -116,10 +116,6 @@ def _init_one_layer(key, cfg: ModelConfig, kind: str) -> Params:
     raise ValueError(kind)
 
 
-def _stack(trees: list[PyTree]) -> PyTree:
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     dt = _dtype(cfg)
     plan = layer_plan(cfg)
@@ -133,20 +129,21 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = init_linear(k_head, (cfg.d_model, cfg.vocab), dt)
 
-    # one stacked tree per block *type* (segments slice into it)
-    counts: dict[str, int] = {}
+    # one stacked tree per block *type* (segments slice into it).  Layer
+    # keys are dealt in plan order; each type's layers are initialised in
+    # one vmapped call, so the stack is built in place (no per-layer
+    # copies to concatenate, and one small program under jit).
+    layer_ids: dict[str, list[int]] = {}
+    n = 0
     for t, c in segs:
         if t != "shared_attn":
-            counts[t] = counts.get(t, 0) + c
-    keys = jax.random.split(k_layers, max(sum(counts.values()), 1))
-    ki = iter(keys)
-    stacks: dict[str, list[Params]] = {t: [] for t in counts}
-    for t, c in segs:
-        if t == "shared_attn":
-            continue
-        for _ in range(c):
-            stacks[t].append(_init_one_layer(next(ki), cfg, t))
-    params["stacks"] = {t: _stack(v) for t, v in stacks.items()}
+            layer_ids.setdefault(t, []).extend(range(n, n + c))
+            n += c
+    keys = jax.random.split(k_layers, max(n, 1))
+    params["stacks"] = {
+        t: jax.vmap(lambda k, _t=t: _init_one_layer(k, cfg, _t))(
+            keys[jnp.asarray(ids)])
+        for t, ids in layer_ids.items()}
     if any(t == "shared_attn" for t, _ in segs):
         params["shared_attn"] = _init_one_layer(k_shared, cfg, "shared_attn")
     return params

@@ -9,12 +9,13 @@ learns each place's current speed from *measured* dispatch wall times, so
 an interfered or throttled submesh is steered around within ~3 requests
 (the paper's 1:4 hysteresis).
 
-On this container, "submeshes" are CPU worker slots driven by the
-threaded runtime; on a real fleet each place maps to a pjit program
-compiled for that submesh shape (the compile cache keyed by place width).
-The scheduler logic is byte-identical in both cases — both engines drive
-the same :class:`~..core.lifecycle.SchedulingKernel` (DESIGN.md §3); that
-is the point.  ``cfg=None`` selects **synthetic-payload mode**: request
+Places are worker slots of the threaded runtime.  With a model config
+every place dispatches the same jitted ``prefill``/``decode_step`` to the
+default JAX device: the XLA CPU backend in the tests, one TPU chip in
+``chip_smoke.py``.  Binding each place to its own device set (a pjit
+program per submesh width) is not done yet.  The scheduler logic is the
+one both engines drive, the :class:`~..core.lifecycle.SchedulingKernel`
+(DESIGN.md §3).  ``cfg=None`` selects **synthetic-payload mode**: request
 payloads are calibrated sleeps (``prefill_s`` / ``decode_s``) instead of
 jitted model dispatches, which is what the overload benchmark uses to
 push the fleet past saturation without paying model-compile time.
@@ -103,8 +104,9 @@ def _bucket(n: int) -> int:
 
 
 class ServingEngine:
-    """PTT-scheduled engine: a real (reduced) model on CPU when ``cfg``
-    is given, calibrated-sleep payloads when ``cfg is None``."""
+    """PTT-scheduled engine: jitted dispatches of the model ``cfg``
+    describes on the default JAX device, or calibrated-sleep payloads
+    when ``cfg is None``."""
 
     def __init__(self, cfg: Optional[ModelConfig], topology: Topology, *,
                  scheduler: str = "DAM-P", seed: int = 0,
@@ -135,7 +137,10 @@ class ServingEngine:
             import jax
             from ..models import decode_step, init_params
             from ..models.transformer import prefill
-            self.params = init_params(cfg, jax.random.PRNGKey(seed))
+            # jitted, so full-width weights are written once in place
+            # instead of per layer and then stacked (twice the memory)
+            self.params = jax.jit(init_params, static_argnums=0)(
+                cfg, jax.random.PRNGKey(seed))
             self._prefill = jax.jit(
                 lambda p, t: prefill(p, cfg, t, max_len),
                 static_argnames=())
@@ -193,6 +198,14 @@ class ServingEngine:
         nxt = int(jnp.argmax(logits[0]))
         req.out_tokens.append(nxt)
         return state, nxt
+
+    def warmup(self, prompt_len: int) -> None:
+        """Run one prefill at ``prompt_len`` and one decode step through
+        the payload code, so every program a request of that length runs
+        is compiled before the first request arrives."""
+        req = Request(0, np.zeros(prompt_len, np.int32), 2)
+        state, tok = self._run_prefill(req)
+        self._run_decode(req, state, tok)
 
     # -- PTT warmup --------------------------------------------------------------
     def prime(self, *task_types: TaskType) -> int:

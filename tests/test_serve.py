@@ -146,6 +146,53 @@ def test_warm_start_priming_is_engine_level():
     eng.run(timeout=60)
 
 
+@pytest.mark.parametrize("raises", [False, True], ids=["clean", "raising"])
+def test_serve_launcher_exit_code(monkeypatch, capsys, raises):
+    """The launcher reports a run in which a payload raised as a failure:
+    the runtime catches the exception so barrier partners never hang, but
+    the process must not exit 0."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "use_compile_cache", lambda: None)
+    if raises:
+        def boom(self, req):
+            raise RuntimeError("device fell over")
+        monkeypatch.setattr(ServingEngine, "_run_prefill", boom)
+    rc = serve.main(["--requests", "2", "--prompt-len", "8",
+                     "--new-tokens", "2"])
+    err = capsys.readouterr().err
+    if raises:
+        assert rc != 0
+        assert "RuntimeError: device fell over" in err
+        assert "2 of 2 requests did not finish" in err
+    else:
+        assert rc == 0 and "FAILED" not in err
+
+
+@pytest.mark.parametrize("env_dir", [None, "/srv/jax-cache"],
+                         ids=["repo-default", "from-environment"])
+def test_compile_cache_directory(monkeypatch, env_dir):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says (JAX reads it itself; nothing is set in code), and otherwise to
+    the fixed ``.jax_cache/`` at the repository root."""
+    import jax
+    from repro.launch import cache
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    where = cache.use_compile_cache()
+    if env_dir is None:
+        assert where == str(cache.REPO_CACHE_DIR)
+        assert cache.REPO_CACHE_DIR.name == ".jax_cache"
+        assert (cache.REPO_CACHE_DIR.parent / "chip_smoke.py").is_file()
+        assert calls == [("jax_compilation_cache_dir", where)]
+    else:
+        assert where == env_dir and calls == []
+
+
 def test_open_loop_poisson_arrival(engine_cfg):
     """Open-loop serving: continuous submission while the runtime runs;
     per-request latency percentiles land in RunMetrics."""

@@ -41,13 +41,14 @@ SUBPROCESS_DRYRUN = textwrap.dedent("""
     import dataclasses, json
     import jax, jax.numpy as jnp
     from repro.configs import ARCHS
+    from repro.launch.mesh import auto_mesh
     from repro.models import init_params, init_decode_state
     from repro.optim import init_opt_state, AdamWConfig
     from repro.parallel import (param_specs, opt_moment_specs, batch_specs,
                                 decode_state_specs, to_named, sharding_ctx)
     from repro.train import make_train_step, make_decode_step
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = auto_mesh((4, 4), ("data", "model"))
     cfg = dataclasses.replace(ARCHS["{arch}"].reduced(), dtype="bfloat16")
     key = jax.random.PRNGKey(0)
     p_shape = jax.eval_shape(lambda: init_params(cfg, key))
@@ -70,10 +71,7 @@ SUBPROCESS_DRYRUN = textwrap.dedent("""
     with mesh, sharding_ctx(mesh):
         c = jax.jit(step, in_shardings=to_named((p_spec, o_spec, b_spec), mesh)
                     ).lower(p_shape, opt_shape, batch).compile()
-    ca = c.cost_analysis()          # dict (jax>=0.5) or list of dicts (older)
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else dict()
-    results["train_flops"] = ca.get("flops", 0.0)
+    results["train_flops"] = c.cost_analysis().get("flops", 0.0)
 
     # decode step
     st_shape = jax.eval_shape(lambda: init_decode_state(cfg, 8, 64))
