@@ -29,7 +29,7 @@ from .interference import (BackgroundApp, LoadCoupledGovernor,
                            mmpp_burst_episodes, mmpp_on_off,
                            mmpp_state_timeline, random_walk_trace,
                            renewal_on_off)
-from .metrics import RequestRecord, RunMetrics, TaskRecord
+from .metrics import RequestRecord, RunMetrics, Span, TaskRecord
 from .multirun import (RunSpec, default_workers, run_cell, run_cells,
                        shutdown_pool)
 from .places import ExecutionPlace, LiveView, ResourcePartition, Topology, \
@@ -56,7 +56,8 @@ __all__ = [
     "dvfs_denver", "governor_profile", "LoadCoupledGovernor",
     "mmpp_burst_episodes", "mmpp_on_off", "mmpp_state_timeline",
     "random_walk_trace", "renewal_on_off",
-    "RequestRecord", "RunMetrics", "TaskRecord", "ExecutionPlace", "LiveView",
+    "RequestRecord", "RunMetrics", "Span", "TaskRecord", "ExecutionPlace",
+    "LiveView",
     "ResourcePartition", "Topology", "haswell", "haswell_cluster",
     "tpu_pod_slices", "tx2", "tx2_xl",
     "PreemptionModel", "mmpp_preemption", "pod_slice_preemption",

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import Counter, defaultdict
+from typing import Optional
 
 
 def percentile(sorted_vals: list[float], q: float) -> float:
@@ -79,6 +80,22 @@ class TaskRecord:
         return f"(C{self.leader},{self.width})"
 
 
+@dataclasses.dataclass(slots=True)
+class Span:
+    """One stretch of host work on the serving path, on the raw
+    ``time.perf_counter()`` clock (the clock of the serving ``Request``
+    stamps, which a device trace can be moved onto).  ``rid`` is the
+    request an engine span serves, ``tid`` the task a runtime span works
+    for, and a ``wake``'s ``cause`` the tid of the task whose commit made
+    the woken task ready (-1: none of them applies)."""
+    name: str
+    t0: float
+    t1: float
+    rid: int = -1
+    tid: int = -1
+    cause: int = -1
+
+
 @dataclasses.dataclass
 class RunMetrics:
     n_cores: int
@@ -132,6 +149,10 @@ class RunMetrics:
     # serving engine's OverloadController at finalize; empty without one
     brownout_transitions: list[tuple] = dataclasses.field(
         default_factory=list)
+    # host spans of the serving path (launch, token wait, scheduler
+    # decisions and lock waits); None unless the threaded runtime was
+    # built with ``spans=True``
+    spans: Optional[list[Span]] = None
 
     def record(self, rec: TaskRecord) -> None:
         self.records.append(rec)

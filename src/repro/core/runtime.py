@@ -59,6 +59,18 @@ fired by a timer thread.  At a revoke edge (all under the runtime lock):
 At a restore edge the cores re-enter the worker loop and steal their way
 back to work.  With no model attached every preemption code path is
 behind a ``None`` check.
+
+Spans
+-----
+Built with ``spans=True``, the runtime keeps ``metrics.spans``, a list of
+:class:`~.metrics.Span` on the raw ``perf_counter`` clock: for each task
+the scheduler decides on, the time spent acquiring the runtime lock
+(``lock_wait``) and the kernel's work once it is held — ``wake``
+(``kernel.wake`` and the push, with the committing task's tid as
+``cause``), ``place`` (pop or steal, and ``choose_place``) and ``commit``
+(PTT feedback and the successor walk, ``on_commit`` included).  Built
+without, ``metrics.spans`` is None and each span site costs one ``is
+None`` test.
 """
 from __future__ import annotations
 
@@ -69,7 +81,7 @@ from typing import Optional
 from .dag import DAG
 from .faults import FaultModel, FaultState, RecoveryPolicy
 from .lifecycle import split_by_priority
-from .metrics import RunMetrics, TaskRecord
+from .metrics import RunMetrics, Span, TaskRecord
 from .preemption import PreemptionModel
 from .queues import BatchingConfig
 from .schedulers import Scheduler
@@ -104,7 +116,8 @@ class ThreadedRuntime:
                  recovery: Optional[RecoveryPolicy] = None,
                  supervisor=None,
                  sharding: Optional[ShardingSpec] = None,
-                 batching: Optional[BatchingConfig] = None):
+                 batching: Optional[BatchingConfig] = None,
+                 spans: bool = False):
         # idle_sleep is only a fallback poll: every work arrival (wake,
         # assignment, requeue, restore) notifies the condition variable,
         # so idle workers do not need a tight poll — 1e-4 here made eight
@@ -141,7 +154,7 @@ class ThreadedRuntime:
         self.work_cv = threading.Condition(self.lock)
         self.outstanding = 0
         self.t0: Optional[float] = None
-        self.metrics = RunMetrics(n_cores=n)
+        self.metrics = RunMetrics(n_cores=n, spans=[] if spans else None)
         self.stop = False
         self._accepting = False         # True between start() and drain()
         self._started = False
@@ -179,10 +192,21 @@ class ThreadedRuntime:
         return 0.0 if self.t0 is None else time.perf_counter() - self.t0
 
     # -- submission -----------------------------------------------------------
-    def _wake(self, task: Task, waker_core: int) -> None:
+    def _wake(self, task: Task, waker_core: int, cause: int = -1) -> None:
+        spans = self.metrics.spans
+        if spans is None:
+            with self.work_cv:
+                self._wake_locked(task, waker_core)
+                self.work_cv.notify_all()
+            return
+        t0 = time.perf_counter()
         with self.work_cv:
+            t1 = time.perf_counter()
             self._wake_locked(task, waker_core)
+            t2 = time.perf_counter()
             self.work_cv.notify_all()
+        spans.append(Span("lock_wait", t0, t1, tid=task.tid))
+        spans.append(Span("wake", t1, t2, tid=task.tid, cause=cause))
 
     def _wake_locked(self, task: Task, waker_core: int) -> None:
         core = self.kernel.wake(task, waker_core)
@@ -207,43 +231,59 @@ class ThreadedRuntime:
 
     # -- worker ---------------------------------------------------------------
     def _pull(self, core: int) -> Optional[_Assigned]:
+        spans = self.metrics.spans
+        if spans is None:
+            with self.lock:
+                return self._pull_locked(core)
+        t0 = time.perf_counter()
         with self.lock:
-            # 1. own AQ head (down cores still finish work already placed
-            #    on them — the grace window)
-            if self.aq[core]:
-                rec = self.aq[core][0]
-                rec.started = True
-                return rec
-            if not self._core_up[core]:
-                return None
-            while True:
-                # 2. own WSQ: oldest HIGH first under priority dequeue,
-                #    else newest LOW (plain work-stealing LIFO)
-                task = self.queues.pop_local(core)
-                stolen = False
-                if task is None:
-                    # 3. steal: most-loaded victim, seeded tie-break, FIFO
-                    #    end, re-run of the place search at the thief
-                    victim = self.queues.pick_victim(core, self.sched.rng)
-                    if victim < 0:
-                        return None
-                    task = self.queues.steal_pop(victim)
-                    stolen = True
-                if (self._fx is not None
-                        and (task.hedge_of or task).committed):
-                    # the losing copy of a hedged pair, parked in a WSQ
-                    # when the winner committed, resolves at pop
-                    self.outstanding -= 1
-                    self.work_cv.notify_all()
-                    continue
-                if stolen:
-                    self.kernel.on_steal(task)
-                if self.batching is not None and task.batch_key is not None:
-                    # coalesce same-key queued LOW work from the queue the
-                    # leader came out of (members were pushed beside it)
-                    self.kernel.form_dispatch(task,
-                                              victim if stolen else core)
-                return self._assign(task, core)
+            t1 = time.perf_counter()
+            placed = not self.aq[core]      # else no decision: AQ head
+            rec = self._pull_locked(core)
+            t2 = time.perf_counter()
+        if placed and rec is not None:
+            tid = rec.task.tid
+            spans.append(Span("lock_wait", t0, t1, tid=tid))
+            spans.append(Span("place", t1, t2, tid=tid))
+        return rec
+
+    def _pull_locked(self, core: int) -> Optional[_Assigned]:
+        # caller holds self.lock
+        # 1. own AQ head (down cores still finish work already placed on
+        #    them — the grace window)
+        if self.aq[core]:
+            rec = self.aq[core][0]
+            rec.started = True
+            return rec
+        if not self._core_up[core]:
+            return None
+        while True:
+            # 2. own WSQ: oldest HIGH first under priority dequeue, else
+            #    newest LOW (plain work-stealing LIFO)
+            task = self.queues.pop_local(core)
+            stolen = False
+            if task is None:
+                # 3. steal: most-loaded victim, seeded tie-break, FIFO end,
+                #    re-run of the place search at the thief
+                victim = self.queues.pick_victim(core, self.sched.rng)
+                if victim < 0:
+                    return None
+                task = self.queues.steal_pop(victim)
+                stolen = True
+            if (self._fx is not None
+                    and (task.hedge_of or task).committed):
+                # the losing copy of a hedged pair, parked in a WSQ when
+                # the winner committed, resolves at pop
+                self.outstanding -= 1
+                self.work_cv.notify_all()
+                continue
+            if stolen:
+                self.kernel.on_steal(task)
+            if self.batching is not None and task.batch_key is not None:
+                # coalesce same-key queued LOW work from the queue the
+                # leader came out of (members were pushed beside it)
+                self.kernel.form_dispatch(task, victim if stolen else core)
+            return self._assign(task, core)
 
     def _assign(self, task: Task, core: int) -> _Assigned:
         # caller holds self.lock
@@ -400,42 +440,63 @@ class ThreadedRuntime:
             src = task.hedge_of or task
         task.t_end = self._now()
         task.place = rec.place
-        observed = task.t_end - task.t_start
         members = task.batch_members or ()
-        with self.lock:
-            # feedback rides the runtime lock: an online reshard() swaps
-            # the plane's shard routing under this same lock, and the
-            # routing read (kernels[shard_of_core[leader]]) must not
-            # interleave with the swap
-            if members:
-                self.kernel.batch_feedback(task, rec.place, observed)
-            else:
-                self.kernel.ptt_feedback(task, rec.place, observed)
-            for c in rec.place.cores:
-                # remove this record from each member AQ (it is at/near head)
-                try:
-                    self.aq[c].remove(rec)
-                except ValueError:
-                    pass
-            self.metrics.record(TaskRecord(
-                type_name=task.type.name, priority=int(task.priority),
-                leader=rec.place.leader, width=rec.place.width,
-                t_ready=src.t_ready, t_start=task.t_start, t_end=task.t_end))
-            if members:
-                base = task.type.batch_base or task.type.name
-                self.metrics.batches.append((task.type.name, tuple(sorted(
-                    [base] + [m.type.name for m in members]))))
-                for m in members:
-                    m.t_start, m.t_end, m.place = (task.t_start, task.t_end,
-                                                   rec.place)
-        for ready in self.kernel.commit_successors(src, lock=self.lock):
-            self._wake(ready, rec.place.leader)
-        for m in members:
-            for ready in self.kernel.commit_successors(m, lock=self.lock):
+        spans = self.metrics.spans
+        if spans is None:
+            with self.lock:
+                self._commit_locked(rec, src, members)
+            for ready in self.kernel.commit_successors(src, lock=self.lock):
                 self._wake(ready, rec.place.leader)
+            for m in members:
+                for ready in self.kernel.commit_successors(m,
+                                                           lock=self.lock):
+                    self._wake(ready, rec.place.leader)
+        else:
+            t0 = time.perf_counter()
+            with self.lock:
+                t1 = time.perf_counter()
+                self._commit_locked(rec, src, members)
+            ready = [*self.kernel.commit_successors(src, lock=self.lock)]
+            for m in members:
+                ready += self.kernel.commit_successors(m, lock=self.lock)
+            t2 = time.perf_counter()
+            spans.append(Span("lock_wait", t0, t1, tid=task.tid))
+            spans.append(Span("commit", t1, t2, tid=task.tid))
+            for r in ready:
+                self._wake(r, rec.place.leader, cause=task.tid)
         with self.work_cv:
             self.outstanding -= 1 + len(members)
             self.work_cv.notify_all()
+
+    def _commit_locked(self, rec: _Assigned, src: Task,
+                       members) -> None:
+        # caller holds self.lock.  Feedback rides the runtime lock: an
+        # online reshard() swaps the plane's shard routing under this same
+        # lock, and the routing read (kernels[shard_of_core[leader]]) must
+        # not interleave with the swap
+        task = rec.task
+        observed = task.t_end - task.t_start
+        if members:
+            self.kernel.batch_feedback(task, rec.place, observed)
+        else:
+            self.kernel.ptt_feedback(task, rec.place, observed)
+        for c in rec.place.cores:
+            # remove this record from each member AQ (it is at/near head)
+            try:
+                self.aq[c].remove(rec)
+            except ValueError:
+                pass
+        self.metrics.record(TaskRecord(
+            type_name=task.type.name, priority=int(task.priority),
+            leader=rec.place.leader, width=rec.place.width,
+            t_ready=src.t_ready, t_start=task.t_start, t_end=task.t_end))
+        if members:
+            base = task.type.batch_base or task.type.name
+            self.metrics.batches.append((task.type.name, tuple(sorted(
+                [base] + [m.type.name for m in members]))))
+            for m in members:
+                m.t_start, m.t_end, m.place = (task.t_start, task.t_end,
+                                               rec.place)
 
     # -- fault recovery (see ``core/faults.py``) ------------------------------
     def _fail(self, rec: _Assigned) -> None:

@@ -25,11 +25,23 @@ Two submission modes:
 * **batch** — ``submit()`` everything, then ``run()`` (the original
   closed-loop shape, still used by the smoke tests);
 * **open loop** — ``run_open_loop(prompts, rate_rps=...)`` starts the
-  runtime first and submits continuously with seeded Poisson
-  inter-arrival gaps, the serving-benchmark shape: queueing delay under
-  interference shows up in the TTFT tail instead of being hidden by
-  batch submission.  Per-request latency percentiles land in
+  runtime first and submits each request at its due time (seeded Poisson
+  inter-arrival gaps summed from the start), the serving-benchmark shape:
+  queueing delay under interference shows up in the TTFT tail instead of
+  being hidden by batch submission, and a late sender shows too, since
+  ``t_submit`` is the due time.  Per-request latency percentiles land in
   ``RunMetrics.request_latency_stats()``.
+
+Timing: every token that leaves the engine (prefill commit, each decode
+commit) appends one ``perf_counter`` stamp to ``Request.t_tokens``; the
+first is ``t_first_token``, the last ``t_done``.  With ``spans=True`` the
+runtime keeps host spans (see ``core/runtime.py``) and each model step
+adds two, carrying the request's rid: ``prefill.launch`` /
+``decode.launch`` from the payload's entry to the host's first block
+(token upload, the jitted step, the sampling launches), and
+``prefill.token_wait`` / ``decode.token_wait``, the blocking read of the
+sampled token.  The two device programs are jitted named functions, so a
+device trace shows them as ``jit_serve_prefill`` and ``jit_serve_decode``.
 
 Robustness under load (this is the serving half of the load-aware
 kernel, DESIGN.md §2):
@@ -70,8 +82,8 @@ from typing import Optional, Sequence
 import numpy as np
 
 from ..configs.base import ModelConfig
-from ..core import (BatchingConfig, Priority, RequestRecord, Task, TaskType,
-                    ThreadedRuntime, Topology, make_scheduler)
+from ..core import (BatchingConfig, Priority, RequestRecord, Span, Task,
+                    TaskType, ThreadedRuntime, Topology, make_scheduler)
 from ..core.dag import DAG
 from ..core.preemption import PreemptionModel
 from .batching import BatchSlot, DecodeBatcher
@@ -94,6 +106,8 @@ class Request:
     reject_cause: str = ""         # "deadline" | "backpressure"
     shed_cause: str = ""           # "deadline" | "brownout"
     tokens_clamped: bool = False   # brownout rung 1 shrank max_new_tokens
+    # perf_counter stamp of each token as it left the engine
+    t_tokens: list[float] = dataclasses.field(default_factory=list)
 
 
 def _bucket(n: int) -> int:
@@ -119,7 +133,8 @@ class ServingEngine:
                  brownout: Optional[BrownoutConfig] = None,
                  sharding=None,
                  batching: Optional[BatchingConfig] = None,
-                 prefill_s: float = 8e-3, decode_s: float = 2e-3):
+                 prefill_s: float = 8e-3, decode_s: float = 2e-3,
+                 spans: bool = False):
         self.cfg = cfg
         self.max_len = max_len
         self.prefill_s = prefill_s
@@ -141,10 +156,16 @@ class ServingEngine:
             # instead of per layer and then stacked (twice the memory)
             self.params = jax.jit(init_params, static_argnums=0)(
                 cfg, jax.random.PRNGKey(seed))
-            self._prefill = jax.jit(
-                lambda p, t: prefill(p, cfg, t, max_len),
-                static_argnames=())
-            self._decode = jax.jit(lambda p, s, t: decode_step(p, cfg, s, t))
+
+            # named, so that a device trace tells the two programs apart
+            def serve_prefill(p, t):
+                return prefill(p, cfg, t, max_len)
+
+            def serve_decode(p, s, t):
+                return decode_step(p, cfg, s, t)
+
+            self._prefill = jax.jit(serve_prefill)
+            self._decode = jax.jit(serve_decode)
         self.sched = make_scheduler(scheduler, topology, seed=seed,
                                     queue_penalty=queue_penalty,
                                     track_load=True)
@@ -152,7 +173,8 @@ class ServingEngine:
                                        preemption=preemption, faults=faults,
                                        recovery=recovery,
                                        supervisor=supervisor,
-                                       sharding=sharding, batching=batching)
+                                       sharding=sharding, batching=batching,
+                                       spans=spans)
         self.warm_start = warm_start
         self.max_pending = max_pending
         self.controller = (OverloadController(brownout)
@@ -175,15 +197,47 @@ class ServingEngine:
         self._flush_thread: Optional[threading.Thread] = None
 
     # -- task payloads ---------------------------------------------------------
+    # a launch uploads the step's input, queues the jitted step and the
+    # sampling on the device, and returns the sampled token unread
+    def _launch_prefill(self, req: Request) -> tuple:
+        import jax.numpy as jnp
+        toks = jnp.asarray(req.prompt)[None, :]
+        logits, state = self._prefill(self.params, toks)
+        return jnp.argmax(logits[0]), state
+
+    def _launch_decode(self, state, tok: int) -> tuple:
+        import jax.numpy as jnp
+        logits, state = self._decode(self.params, state,
+                                     jnp.asarray([tok], jnp.int32))
+        return jnp.argmax(logits[0]), state
+
+    @staticmethod
+    def _read_token(spans: list, rid: int, t0: float, best,
+                    launch_span: str, wait_span: str) -> int:
+        """Reads the sampled token back, which blocks until its step (and
+        whatever the device runs ahead of it) is done, and records the
+        step's launch (from ``t0``) and token-wait spans."""
+        t1 = time.perf_counter()
+        nxt = int(best)
+        t2 = time.perf_counter()
+        spans.append(Span(launch_span, t0, t1, rid=rid))
+        spans.append(Span(wait_span, t1, t2, rid=rid))
+        return nxt
+
     def _run_prefill(self, req: Request) -> tuple:
         if self.cfg is None:
             time.sleep(self.prefill_s)
             req.out_tokens.append(0)
             return None, 0
-        import jax.numpy as jnp
-        toks = jnp.asarray(req.prompt)[None, :]
-        logits, state = self._prefill(self.params, toks)
-        nxt = int(jnp.argmax(logits[0]))
+        spans = self.runtime.metrics.spans
+        if spans is None:
+            best, state = self._launch_prefill(req)
+            nxt = int(best)
+        else:
+            t0 = time.perf_counter()
+            best, state = self._launch_prefill(req)
+            nxt = self._read_token(spans, req.rid, t0, best,
+                                   "prefill.launch", "prefill.token_wait")
         req.out_tokens.append(nxt)
         return state, nxt
 
@@ -192,10 +246,15 @@ class ServingEngine:
             time.sleep(self.decode_s)
             req.out_tokens.append(0)
             return None, 0
-        import jax.numpy as jnp
-        logits, state = self._decode(self.params, state,
-                                     jnp.asarray([tok], jnp.int32))
-        nxt = int(jnp.argmax(logits[0]))
+        spans = self.runtime.metrics.spans
+        if spans is None:
+            best, state = self._launch_decode(state, tok)
+            nxt = int(best)
+        else:
+            t0 = time.perf_counter()
+            best, state = self._launch_decode(state, tok)
+            nxt = self._read_token(spans, req.rid, t0, best,
+                                   "decode.launch", "decode.token_wait")
         req.out_tokens.append(nxt)
         return state, nxt
 
@@ -280,8 +339,8 @@ class ServingEngine:
         with self._admit_lock:
             return self.controller.update(signal, self._elapsed())
 
-    def _request_done(self, req: Request) -> None:
-        req.t_done = time.perf_counter()
+    def _request_done(self, req: Request, now: float) -> None:
+        req.t_done = now
         with self._admit_lock:
             self._pending -= 1
         self._update_controller()
@@ -289,10 +348,17 @@ class ServingEngine:
     # -- request -> dynamic DAG --------------------------------------------------
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 8,
                deadline_s: float = 0.0, tier: str = "low") -> Request:
+        return self._submit(prompt, max_new_tokens, deadline_s, tier,
+                            time.perf_counter())
+
+    def _submit(self, prompt: np.ndarray, max_new_tokens: int,
+                deadline_s: float, tier: str, t_submit: float) -> Request:
+        """Admits one request; ``t_submit`` is when it was due, on the
+        ``perf_counter`` clock (now, unless a sender is late)."""
         self._rid += 1
         req = Request(self._rid, np.asarray(prompt).astype(np.int32),
                       max_new_tokens, tier=tier,
-                      t_submit=time.perf_counter(), deadline_s=deadline_s)
+                      t_submit=t_submit, deadline_s=deadline_s)
         self.requests[req.rid] = req
 
         def _reject(cause: str) -> Request:
@@ -367,16 +433,17 @@ class ServingEngine:
         # first token leaves the engine at prefill *commit* — after any
         # injected slowdown, when a real client would see it
         req, ctx = task.args
-        req.t_first_token = time.perf_counter()
+        now = time.perf_counter()
+        req.t_first_token = now
+        req.t_tokens.append(now)
         if req.max_new_tokens <= 1:
-            self._request_done(req)
+            self._request_done(req, now)
             return []
         if self.batcher is not None:
             # continuous batching: the ready decode step parks in the
             # batcher (outside the WSQs — HIGH prefills are never queued
             # behind batch fill) and dispatches when a trigger fires
-            return self._groups_to_tasks(
-                self.batcher.add(req, ctx, time.perf_counter()))
+            return self._groups_to_tasks(self.batcher.add(req, ctx, now))
         return [self._make_decode_task(req, ctx)]
 
     def _make_decode_task(self, req: Request, ctx: dict) -> Task:
@@ -414,9 +481,12 @@ class ServingEngine:
     def _decode_commit(self, task: Task) -> list[Task]:
         req, ctx = task.args
         ctx["step"] += 1
-        if not req.shed and ctx["step"] < req.max_new_tokens - 1:
-            return [self._make_decode_task(req, ctx)]
-        self._request_done(req)
+        now = time.perf_counter()
+        if not req.shed:                 # a shed step served no token
+            req.t_tokens.append(now)
+            if ctx["step"] < req.max_new_tokens - 1:
+                return [self._make_decode_task(req, ctx)]
+        self._request_done(req, now)
         return []
 
     # -- batched decode path (continuous batching) -------------------------------
@@ -463,8 +533,9 @@ class ServingEngine:
             req = s.req
             if not req.shed:
                 s.ctx["step"] += 1
+                req.t_tokens.append(now)
             if req.shed or s.ctx["step"] >= req.max_new_tokens - 1:
-                self._request_done(req)
+                self._request_done(req, now)
             else:
                 ready.extend(self._groups_to_tasks(
                     self.batcher.readd(s, now)))
@@ -525,20 +596,27 @@ class ServingEngine:
                       arrival_seed: int = 0, deadline_s: float = 0.0,
                       tier: str = "low", timeout: float = 300.0):
         """Open-loop serving: start the runtime, then submit one request
-        per prompt with Poisson inter-arrival gaps (seeded ``expovariate``
-        at ``rate_rps`` requests/s) while earlier requests execute.
-        ``deadline_s`` > 0 puts every request under that deadline
-        (admission rejection + decode shedding).  Returns the
-        :class:`RunMetrics` with per-request latency records attached."""
+        per prompt at its due time, the start plus the sum of the Poisson
+        inter-arrival gaps so far (seeded ``expovariate`` at ``rate_rps``
+        requests/s), while earlier requests execute.  Each request's
+        ``t_submit`` is its due time, so a sender that falls behind shows
+        in the latencies.  ``deadline_s`` > 0 puts every request under
+        that deadline (admission rejection + decode shedding).  Returns
+        the :class:`RunMetrics` with per-request latency records
+        attached."""
         arrivals = random.Random(f"serve-arrival:{arrival_seed}")
         self.runtime.start()
         if self.batcher is not None:
             self._start_flusher()
+        due = time.perf_counter()
         for i, prompt in enumerate(prompts):
             if i:
-                time.sleep(arrivals.expovariate(rate_rps))
-            self.submit(np.asarray(prompt), max_new_tokens=max_new_tokens,
-                        deadline_s=deadline_s, tier=tier)
+                due += arrivals.expovariate(rate_rps)
+            wait = due - time.perf_counter()
+            if wait > 0:
+                time.sleep(wait)
+            self._submit(np.asarray(prompt), max_new_tokens, deadline_s,
+                         tier, due)
         if self.batcher is not None:
             m = self._drain_batched(timeout)
         else:
