@@ -149,6 +149,10 @@ class RunMetrics:
     # serving engine's OverloadController at finalize; empty without one
     brownout_transitions: list[tuple] = dataclasses.field(
         default_factory=list)
+    # model decode steps the serving engine launched, and those whose
+    # input state the step consumed (donated, so written in place)
+    decode_steps: int = 0
+    decode_in_place: int = 0
     # host spans of the serving path (launch, token wait, scheduler
     # decisions and lock waits); None unless the threaded runtime was
     # built with ``spans=True``

@@ -420,12 +420,16 @@ _STATE_KEY = {"attn": "kv", "attn_moe": "kv", "shared_attn": "shared_kv",
 
 
 def _block_decode(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
-                  st: PyTree) -> tuple[jax.Array, PyTree]:
+                  st: PyTree, layer: jax.Array | None = None
+                  ) -> tuple[jax.Array, PyTree]:
+    """One layer's decode.  Attention kinds take the whole cache stack and
+    ``layer``, their index into it; the others take their own state."""
     if kind in ("attn", "attn_moe", "shared_attn"):
         h = rms_norm(x, p["ln1"], cfg.rms_eps)
         y, st = attention_decode(
-            p["attn"], h, st, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta)
+            p["attn"], h, st, layer, n_heads=cfg.n_heads,
+            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.resolved_head_dim,
+            rope_theta=cfg.rope_theta)
         x = x + y
         h = rms_norm(x, p["ln2"], cfg.rms_eps)
         if kind == "attn_moe":
@@ -456,8 +460,11 @@ def decode_step(params: Params, cfg: ModelConfig, state: PyTree,
                 tokens: jax.Array) -> tuple[jax.Array, PyTree]:
     """One decode step.  tokens: [B] int32 -> (logits [B, V], new state).
 
-    Scans over each stacked layer group; the matching state stack is the
-    scan carry input, so compile time stays O(#segments), not O(#layers).
+    Scans over each stacked layer group, so compile time stays
+    O(#segments), not O(#layers).  The KV cache stacks ride the scan carry
+    whole and each layer writes its one new row into them (in place when
+    the caller donates ``state``); the small recurrent states are the scan
+    input and output of their segment.
     """
     x = params["embed"][tokens][:, None, :]          # [B, 1, d]
     segs = _segments(layer_plan(cfg))
@@ -465,30 +472,40 @@ def decode_step(params: Params, cfg: ModelConfig, state: PyTree,
     param_off: dict[str, int] = {}    # running offset into each param stack
     new_state = dict(state)
 
+    def layer_params(layer_p):
+        return params["shared_attn"] if layer_p is None else layer_p
+
     for kind, count in segs:
         skey = _STATE_KEY[kind]
         s0 = state_off.get(skey, 0)
         state_off[skey] = s0 + count
-        st_stack = jax.tree.map(lambda a: a[s0:s0 + count], state[skey])
-
-        if kind == "shared_attn":
-            # weight-shared block: scan over its per-application caches only
-            def body(x_c, sl, _kind=kind):
-                return _block_decode(cfg, _kind, params["shared_attn"], x_c, sl)
-
-            x, st_new = jax.lax.scan(body, x, st_stack)
+        if kind == "shared_attn":     # one weight-shared block
+            p_stack = None
         else:
             p0 = param_off.get(kind, 0)
             param_off[kind] = p0 + count
             p_stack = jax.tree.map(lambda a: a[p0:p0 + count],
                                    params["stacks"][kind])
 
-            def body(x_c, inp, _kind=kind):
-                layer_p, sl = inp
-                return _block_decode(cfg, _kind, layer_p, x_c, sl)
+        if skey in ("kv", "shared_kv"):
+            def body(carry, inp, _kind=kind):
+                x_c, kv = carry
+                layer_p, layer = inp
+                return _block_decode(cfg, _kind, layer_params(layer_p), x_c,
+                                     kv, layer), None
 
-            x, st_new = jax.lax.scan(body, x, (p_stack, st_stack))
+            layers = s0 + jnp.arange(count, dtype=jnp.int32)
+            (x, new_state[skey]), _ = jax.lax.scan(
+                body, (x, new_state[skey]), (p_stack, layers))
+            continue
 
+        st_stack = jax.tree.map(lambda a: a[s0:s0 + count], state[skey])
+
+        def body(x_c, inp, _kind=kind):
+            layer_p, sl = inp
+            return _block_decode(cfg, _kind, layer_params(layer_p), x_c, sl)
+
+        x, st_new = jax.lax.scan(body, x, (p_stack, st_stack))
         new_state[skey] = jax.tree.map(
             lambda full, new, _s0=s0: jax.lax.dynamic_update_slice_in_dim(
                 full, new.astype(full.dtype), _s0, axis=0),

@@ -225,6 +225,16 @@ def sharding_ctx(mesh: Mesh):
         _ACTIVE_MESH.pop()
 
 
+def shards_of(dim: int, axis: str) -> int:
+    """How many shards the active mesh cuts a dim of size ``dim`` into
+    over ``axis``: 1 outside ``sharding_ctx``, or where the axis does not
+    divide the dim (``sanitize`` then replicates it)."""
+    if not _ACTIVE_MESH:
+        return 1
+    n = _ACTIVE_MESH[-1].shape.get(axis, 1)
+    return n if dim % n == 0 else 1
+
+
 def constrain(x: jax.Array, axes: tuple) -> jax.Array:
     if not _ACTIVE_MESH:
         return x

@@ -165,7 +165,9 @@ class ServingEngine:
                 return decode_step(p, cfg, s, t)
 
             self._prefill = jax.jit(serve_prefill)
-            self._decode = jax.jit(serve_decode)
+            # the step owns its input state: each layer writes its new K/V
+            # row into the donated cache instead of a fresh copy
+            self._decode = jax.jit(serve_decode, donate_argnums=(1,))
         self.sched = make_scheduler(scheduler, topology, seed=seed,
                                     queue_penalty=queue_penalty,
                                     track_load=True)
@@ -184,6 +186,7 @@ class ServingEngine:
         self._rid = 0
         self._pending = 0              # admitted, not yet finalized
         self._admit_lock = threading.Lock()
+        self._step_lock = threading.Lock()     # decode step counters
         self._primed: set[str] = set()
         # hoisted task types: one shared decode TaskType per engine and
         # one prefill TaskType per prompt-length bucket — per-request
@@ -206,10 +209,18 @@ class ServingEngine:
         return jnp.argmax(logits[0]), state
 
     def _launch_decode(self, state, tok: int) -> tuple:
+        import jax
         import jax.numpy as jnp
-        logits, state = self._decode(self.params, state,
-                                     jnp.asarray([tok], jnp.int32))
-        return jnp.argmax(logits[0]), state
+        logits, new_state = self._decode(self.params, state,
+                                         jnp.asarray([tok], jnp.int32))
+        # a donation XLA refused leaves the input alive: the step then
+        # wrote a fresh copy of the state
+        in_place = all(a.is_deleted() for a in jax.tree.leaves(state))
+        m = self.runtime.metrics
+        with self._step_lock:
+            m.decode_steps += 1
+            m.decode_in_place += in_place
+        return jnp.argmax(logits[0]), new_state
 
     @staticmethod
     def _read_token(spans: list, rid: int, t0: float, best,

@@ -78,6 +78,51 @@ def test_prefill_decode_matches_forward(arch):
     assert rel < 5e-3, f"{arch}: rel err {rel}"
 
 
+@pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_successive_decode_steps_match_forward(arch):
+    """Two requests of different lengths share a B=2 decode state and take
+    N donated steps.  Each step's logits match ``forward`` over the same
+    tokens; the longer request's last row lands at T-1 and the shorter one
+    crosses a 128-row block; every cache row at or past its length stays
+    zero."""
+    cfg = dataclasses.replace(ARCHS[arch].reduced(), capacity_factor=16.0)
+    params = init_params(cfg, KEY)
+    p, n_steps, t_max = cfg.frontend_len, 4, 200
+    # cache rows held after each prefill: 126 and T - N
+    lens = [126 - p, t_max - n_steps - p]
+    toks = jax.random.randint(KEY, (2, lens[1] + n_steps), 0, cfg.vocab)
+    fe = _batch(cfg).get("frontend")
+    seqs = [toks[b:b + 1, :lens[b] + n_steps] for b in range(2)]
+    fes = [None if fe is None else fe[b:b + 1] for b in range(2)]
+    full = [forward(params, cfg, seqs[b], fes[b])[0][0] for b in range(2)]
+    parts = [prefill(params, cfg, seqs[b][:, :lens[b]], t_max, fes[b])[1]
+             for b in range(2)]
+    state = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=1),
+                         *parts)
+    step = jax.jit(lambda p_, s, t: decode_step(p_, cfg, s, t),
+                   donate_argnums=1)
+    for i in range(n_steps):
+        tok = jnp.stack([seqs[b][0, lens[b] + i] for b in range(2)])
+        logits, state = step(params, state, tok)
+        for b in range(2):
+            want = full[b][lens[b] + i]
+            rel = float(jnp.abs(logits[b] - want).max() /
+                        jnp.abs(want).max())
+            assert rel < 5e-3, f"{arch}: step {i}, element {b}: {rel}"
+        for skey in ("kv", "shared_kv"):
+            if skey not in state:
+                continue
+            kv = state[skey]
+            assert (kv["length"] ==
+                    jnp.asarray(lens) + p + i + 1).all()
+            for b in range(2):
+                n = p + lens[b] + i + 1
+                assert n <= t_max
+                for leaf in ("k", "v"):
+                    assert (kv[leaf][:, b, n:] == 0).all()
+                    assert (kv[leaf][:, b, n - 1] != 0).any()
+
+
 def test_layer_plans():
     assert layer_plan(ARCHS["granite-8b"]) == ["attn"] * 36
     assert layer_plan(ARCHS["qwen3-moe-30b-a3b"]) == ["attn_moe"] * 48

@@ -5,8 +5,9 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS
-from repro.core import tpu_pod_slices
+from repro.core import task_faults, tpu_pod_slices
 from repro.serve import BrownoutConfig, ServingEngine
+from repro.serve.engine import Request
 
 
 @pytest.fixture(scope="module")
@@ -213,3 +214,28 @@ def test_open_loop_poisson_arrival(engine_cfg):
     es = eng.latency_stats()
     assert es["completed"] == 3
     assert es["ttft_ms_p50"] <= es["ttft_ms_p99"]
+
+
+@pytest.mark.parametrize("p_fail", [0.0, 0.3], ids=["clean", "failstop"])
+def test_decode_step_consumes_its_state(p_fail):
+    """The jitted decode step owns its input state: the old K/V cache is
+    consumed (donated, so written in place) and every step counts as in
+    place.  A fail-stop fault strikes after the payload ran, and its retry
+    runs from the state the payload already stored: every request still
+    finishes, with no payload error on a consumed buffer."""
+    cfg = ARCHS["stablelm-3b"].reduced()
+    eng = ServingEngine(cfg, tpu_pod_slices(2, 2), max_len=48,
+                        faults=task_faults(seed=3, p_fail=p_fail))
+    warm = Request(0, np.zeros(16, np.int32), 2)
+    state, tok = eng._run_prefill(warm)
+    old_k = state["kv"]["k"]
+    eng._run_decode(warm, state, tok)
+    assert old_k.is_deleted()
+    rng = np.random.default_rng(2)
+    for _ in range(4):
+        eng.submit(rng.integers(0, cfg.vocab, 16), max_new_tokens=4)
+    m = eng.run(timeout=300)
+    assert m.errors == []
+    assert eng.latency_stats()["completed"] == 4
+    assert (m.faults_failstop > 0) == (p_fail > 0)
+    assert m.decode_in_place == m.decode_steps >= 1 + 4 * 3

@@ -1,6 +1,7 @@
 """Sharding rules + a small-scale dry-run executed in a subprocess (the
 device-count flag must not leak into this test process)."""
 import json
+import math
 import os
 import subprocess
 import sys
@@ -38,7 +39,7 @@ def test_sanitize_pads_rank():
 SUBPROCESS_DRYRUN = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
-    import dataclasses, json
+    import dataclasses, json, math, re
     import jax, jax.numpy as jnp
     from repro.configs import ARCHS
     from repro.launch.mesh import auto_mesh
@@ -73,35 +74,68 @@ SUBPROCESS_DRYRUN = textwrap.dedent("""
                     ).lower(p_shape, opt_shape, batch).compile()
     results["train_flops"] = c.cost_analysis().get("flops", 0.0)
 
-    # decode step
+    # decode step, its state donated as the serving engine donates it
     st_shape = jax.eval_shape(lambda: init_decode_state(cfg, 8, 64))
     st_spec = decode_state_specs(st_shape, mesh)
     toks = jax.ShapeDtypeStruct((8,), jnp.int32)
     dstep = make_decode_step(cfg)
     with mesh, sharding_ctx(mesh):
         c2 = jax.jit(dstep, in_shardings=to_named(
-            (p_spec, st_spec, batch_specs(toks, mesh)), mesh)
-        ).lower(p_shape, st_shape, toks).compile()
+            (p_spec, st_spec, batch_specs(toks, mesh)), mesh),
+            donate_argnums=(1,)).lower(p_shape, st_shape, toks).compile()
     results["decode_ok"] = True
+    # KV cache stack shapes, and the element count of each all-gather
+    results["kv_stacks"] = [st_shape[k]["k"].shape
+                            for k in ("kv", "shared_kv") if k in st_shape]
+    gather = r"= \\w+\\[([\\d,]*)\\][^=]* all-gather(?:-start)?\\("
+    results["decode_gathers"] = [
+        math.prod(int(d) for d in dims.split(",") if d)
+        for dims in re.findall(gather, c2.as_text())]
     print(json.dumps(results))
 """)
+
+
+@pytest.fixture(scope="module")
+def dryrun():
+    """Runs the 16-device dry run of an arch in a subprocess, once per
+    arch for this module's tests."""
+    runs = {}
+
+    def get(arch):
+        if arch not in runs:
+            env = dict(os.environ, PYTHONPATH=os.path.join(
+                os.path.dirname(__file__), "..", "src"))
+            out = subprocess.run(
+                [sys.executable, "-c", SUBPROCESS_DRYRUN.format(arch=arch)],
+                capture_output=True, text=True, env=env, timeout=600)
+            assert out.returncode == 0, out.stderr[-3000:]
+            runs[arch] = json.loads(out.stdout.strip().splitlines()[-1])
+        return runs[arch]
+
+    return get
 
 
 @pytest.mark.parametrize("arch", ["granite-8b", "qwen3-moe-30b-a3b",
                                   "zamba2-1.2b", "xlstm-125m",
                                   "internvl2-76b"])
-def test_reduced_dryrun_on_16_fake_devices(arch):
+def test_reduced_dryrun_on_16_fake_devices(arch, dryrun):
     """lower+compile of train AND decode for a reduced config on a real
     (4,4) mesh — the shape-divisibility/sharding logic must hold end to
     end, not just on the production mesh."""
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
-    out = subprocess.run(
-        [sys.executable, "-c", SUBPROCESS_DRYRUN.format(arch=arch)],
-        capture_output=True, text=True, env=env, timeout=600)
-    assert out.returncode == 0, out.stderr[-3000:]
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    assert res["decode_ok"]
+    assert dryrun(arch)["decode_ok"]
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "qwen3-moe-30b-a3b",
+                                  "zamba2-1.2b"])
+def test_sp_decode_gathers_no_cache_on_16_fake_devices(arch, dryrun):
+    """With the cache sequence-sharded over "model", the decode step's new
+    K/V row stays a shard-local write: no all-gather in the compiled step
+    brings a device as much as one layer's share of a KV cache stack."""
+    res = dryrun(arch)
+    assert res["kv_stacks"]
+    for _layers, *layer_shape in res["kv_stacks"]:
+        share = math.prod(layer_shape) // 16
+        assert max(res["decode_gathers"], default=0) < share, res
 
 
 def test_param_specs_cover_all_leaves():
