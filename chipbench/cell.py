@@ -15,6 +15,11 @@ client's one request in flight.  After the window every request due in it
 is awaited, up to a minute past the close; one that never completes has
 failed.  Then the device's peak memory is read, the program is freed, and
 the plain reference runs over a seeded sample of finished requests.
+
+A traced run builds the engine with its host spans on and carries them
+(``RunData.spans``); an untraced run keeps them off, so that the
+end-to-end metrics are read with nothing added to the serving path.  Every
+run carries each request's token stamps and the engine's counters.
 """
 from __future__ import annotations
 
@@ -64,6 +69,7 @@ class Req(NamedTuple):
     prompt_len: int
     n_out: int
     max_new: int
+    tokens: tuple = ()    # each token's stamp (``Request.t_tokens``)
 
     @property
     def finished(self) -> bool:
@@ -91,6 +97,12 @@ class RunData:
     tokens_in_window: int
     events: Optional[list]            # trace.Event list of a traced run
     peak: Optional[dict]              # peaks.json entry of the device
+    # the engine's host spans (``RunMetrics.spans``) of a traced run
+    spans: Optional[list] = None
+    # the engine's counters over the whole run, set-up included: every
+    # numeric field of its drained ``RunMetrics`` (``decode_steps``,
+    # ``decode_in_place``, ...)
+    counters: Optional[dict] = None
 
 
 def check_device(chips: int) -> dict:
@@ -131,7 +143,7 @@ class _Sent:
         self.due, self.submit, self.req, self.prompt = due, submit, req, prompt
 
 
-def _build(spec: Spec, seed: int):
+def _build(spec: Spec, seed: int, spans: bool):
     import jax
     from repro.configs.base import ModelConfig
     from repro.core import tpu_pod_slices
@@ -141,7 +153,7 @@ def _build(spec: Spec, seed: int):
     engine = ServingEngine(
         cfg, tpu_pod_slices(serving["pods"], serving["slices_per_pod"]),
         scheduler=serving["scheduler"], max_len=serving["max_len"],
-        seed=model_seed(seed))
+        seed=model_seed(seed), spans=spans)
     jax.block_until_ready(engine.params)
     return engine
 
@@ -317,7 +329,7 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, *,
         seconds = min(seconds, TRACE_S)
     import jax
     log = CompileLog()
-    engine = _build(spec, seed)
+    engine = _build(spec, seed, spans=trace)
     work, prompts = _work(spec, seed, seconds)
     closed = spec.traffic["loop"] == "closed"
     flat = [it for q in work for it in q] if closed else work
@@ -350,9 +362,12 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, *,
     tasks = [Task(r.type_name, r.t_ready + rt0, r.t_start + rt0,
                   r.t_end + rt0) for r in metrics.records]
     reqs = [Req(s.due, s.submit, s.req.t_first_token, s.req.t_done,
-                len(s.prompt), len(s.req.out_tokens), s.req.max_new_tokens)
+                len(s.prompt), len(s.req.out_tokens), s.req.max_new_tokens,
+                tuple(s.req.t_tokens))
             for s in sent]
     outs = [(s.prompt, list(s.req.out_tokens)) for s in sent]
+    spans = None if metrics.spans is None else list(metrics.spans)
+    counters = counters_of(metrics)
     del engine, metrics, sent
     gc.collect()
 
@@ -363,7 +378,7 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, *,
         events = load(trace_dir, capture.marker_ns)
         peak = counts.peaks(device["kind"]) if require_chip else None
     data = RunData(spec, seconds, (t0, t1), setup_s, reqs, tasks, compiles,
-                   tokens_in_window, events, peak)
+                   tokens_in_window, events, peak, spans, counters)
     chosen = spec.per_layer if trace else spec.end_to_end
     out_metrics, unread = {}, []
     for m in chosen:
@@ -397,6 +412,16 @@ def run(spec: Spec, seed: int, seconds: float, trace: bool, *,
             "latency": latency(data),
             "rows": [outs[i] for i in pick], "data": data}
     return result, info
+
+
+def counters_of(metrics) -> dict:
+    """Every numeric field of a ``RunMetrics``, by name."""
+    out = {}
+    for f in dataclasses.fields(metrics):
+        v = getattr(metrics, f.name)
+        if isinstance(v, (int, float)) and not isinstance(v, bool):
+            out[f.name] = v
+    return out
 
 
 def latency(data: RunData) -> dict:

@@ -15,6 +15,13 @@ Equations.  They follow the blocks as this repository writes them, in
 float32 at ``Precision.HIGHEST``; each family module lists where they
 depart from the published model.
 
+Families.  A family module's ``plan(m, seed)`` lists the blocks in order.
+A block is named (``"attn"``, ``"mamba"``: the blocks below) or is a
+layer that the module makes itself with ``layer(weights, block)``, from
+its own weight draws and equations; such a block may read the embedding
+output ``x0`` and any key of the configuration's ``model`` group
+(``Model.get``), and the control rounds its weights as it rounds these.
+
 Control.  ``quant="int8"`` rounds every weight matrix to int8 with one
 scale per output column (per row for the embedding) before use, and
 ``quant="fp8"`` to float8 e4m3 with the same scales: the weight-only
@@ -23,7 +30,8 @@ steps below bfloat16 that a faster decode would be tempted to take.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import inspect
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,15 +58,35 @@ class Model:
     ssm_state: int = 0
     mamba_head_dim: int = 64
     shared_attn_every: int = 0
+    # every other key of the group, as sorted (key, value) pairs with lists
+    # made tuples, so that the sizes stay hashable (a static argument)
+    extra: tuple = ()
 
     @classmethod
     def from_dict(cls, d: dict) -> "Model":
-        names = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in names})
+        names = {f.name for f in dataclasses.fields(cls)} - {"extra"}
+        return cls(**{k: v for k, v in d.items() if k in names},
+                   extra=tuple(sorted((k, _frozen(v)) for k, v in d.items()
+                                      if k not in names)))
+
+    def get(self, key: str, default: Any = None) -> Any:
+        """The group's ``key``, a field or not; ``default`` where the
+        group has no such key."""
+        if key != "extra" and key in self.__dataclass_fields__:
+            return getattr(self, key)
+        return dict(self.extra).get(key, default)
 
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+
+def _frozen(v):
+    if isinstance(v, dict):
+        return tuple(sorted((k, _frozen(x)) for k, x in v.items()))
+    if isinstance(v, list):
+        return tuple(_frozen(x) for x in v)
+    return v
 
 
 # -- weights -----------------------------------------------------------------
@@ -243,41 +271,64 @@ def _gaps(x, head, targets, m: Model):
 
 def run_layers(plan, m: Model, seed: int, tokens, targets,
                quant: Optional[str] = None):
-    """Runs a family's ``plan`` (a list of ``(block, key)`` with ``block``
-    ``"attn"`` or ``"mamba"``) over token rows [n, L] and returns, as
-    numpy, the logit gaps [k, n, L] of the target token sets [k, n, L] and
-    the reference's own best tokens [n, L]."""
+    """Runs a family's ``plan`` (a list of ``(block, key)``, ``block`` a
+    name of ``_LAYERS`` or a layer made by ``layer``) over token rows
+    [n, L] and returns, as numpy, the logit gaps [k, n, L] of the target
+    token sets [k, n, L] and the reference's own best tokens [n, L].  A
+    block whose equations take ``x0`` gets the embedding output."""
     import numpy as np
     k_embed, k_head, _, _ = root_keys(seed)
-    x = _embed_rows(k_embed, jnp.asarray(tokens, jnp.int32), m, quant)
+    x = x0 = _embed_rows(k_embed, jnp.asarray(tokens, jnp.int32), m, quant)
     for block, key in plan:
-        x = _LAYERS[block](key, x, m, quant)
-    gaps, top = _head_gaps(k_head, x, jnp.asarray(targets, jnp.int32), m,
-                           quant)
+        run = _LAYERS[block] if isinstance(block, str) else block
+        if "x0" in inspect.signature(run).parameters:
+            x = run(key, x, m, quant, x0)
+        else:
+            x = run(key, x, m, quant)
+    gaps, top = _head_gaps(k_embed, k_head, x,
+                           jnp.asarray(targets, jnp.int32), m, quant)
     return np.asarray(gaps), np.asarray(top)
 
 
+def layer(weights, block):
+    """A plan's block from a weight maker ``weights(key, m) -> dict`` and
+    float32 equations ``block(w, x, m)``, or ``block(w, x, m, x0)`` for
+    one that reads the embedding output: the weights are drawn in the
+    served dtype, then made float32 and, under the control, rounded
+    (``to_f32``).  Jitted, with the sizes and ``quant`` static."""
+    if "x0" in inspect.signature(block).parameters:
+        def run(key, x, m, quant, x0):
+            return block(to_f32(weights(key, m), quant), x, m, x0)
+    else:
+        def run(key, x, m, quant):
+            return block(to_f32(weights(key, m), quant), x, m)
+    return jax.jit(run, static_argnums=(2, 3))
+
+
 def _embed(k_embed, tokens, m, quant):
+    return _embed_f32(k_embed, m, quant)[tokens]
+
+
+def _embed_f32(k_embed, m, quant):
     return _quantize(embed_weight(k_embed, m).astype(jnp.float32), quant,
-                     axis=1)[tokens]
+                     axis=1)
 
 
-def _attn_layer(key, x, m, quant):
-    return attention_block(to_f32(attn_weights(key, m), quant), x, m)
-
-
-def _mamba_layer(key, x, m, quant):
-    return mamba_block(to_f32(mamba_weights(key, m), quant), x, m)
-
-
-def _head(k_head, x, targets, m, quant):
-    head = _quantize(head_weight(k_head, m).astype(jnp.float32), quant, 0)
+def _head(k_embed, k_head, x, targets, m, quant):
+    """The gaps under the output head: the embedding matrix transposed
+    where the group sets ``tie_embeddings``, as the program does, else the
+    head's own draw."""
+    if m.get("tie_embeddings", False):
+        head = _embed_f32(k_embed, m, quant).T
+    else:
+        head = _quantize(head_weight(k_head, m).astype(jnp.float32), quant,
+                         0)
     return _gaps(x, head, targets, m)
 
 
 _LAYERS = {
-    "attn": jax.jit(_attn_layer, static_argnums=(2, 3)),
-    "mamba": jax.jit(_mamba_layer, static_argnums=(2, 3)),
+    "attn": layer(attn_weights, attention_block),
+    "mamba": layer(mamba_weights, mamba_block),
 }
 _embed_rows = jax.jit(_embed, static_argnums=(2, 3))
-_head_gaps = jax.jit(_head, static_argnums=(3, 4))
+_head_gaps = jax.jit(_head, static_argnums=(4, 5))

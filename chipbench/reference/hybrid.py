@@ -1,7 +1,15 @@
 """Plain float32 reference of the repository's hybrid Mamba-2 family
-(``ARCHS["zamba2-1.2b"]``).  No cell runs it yet: the family cannot take
-the published Zamba2-1.2B widths (see the list below), so it stands ready
-for a hybrid cell once the program can.
+(``ARCHS["zamba2-1.2b"]``).  No cell runs it yet.  A hybrid cell at
+published widths (Zamba2-7B-Instruct, or Zamba2-1.2B) needs the program
+changed first, not only a cell: separate Mamba and attention head counts,
+B/C groups, ``hybrid_layer_ids`` and adapter sizes in
+``configs/base.py``; grouped B/C, ``dt_bias``, ``A`` and the ``D`` skip in
+``models/mamba2.py`` and ``kernels/ssd_scan.py``; shared blocks (two,
+alternating, in Zamba2-7B) fed ``concat(hidden, embedding)``, with
+per-application LoRA adapters and a gated-GELU FFN, in
+``models/transformer.py``; and, for Zamba2-7B, head dim 224 in the flash
+path.  Its reference is then a family module of its own, whose blocks
+read the embedding output (``common.layer``).
 
 ``n_layers`` pre-norm Mamba-2 blocks; after every ``shared_attn_every``-th
 of them the one weight-shared attention + SwiGLU block (its weights drawn

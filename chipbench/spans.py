@@ -2,11 +2,11 @@
 
 ``ServingEngine(..., spans=True)`` keeps ``RunMetrics.spans``: records
 with ``name``, ``t0``, ``t1`` (raw ``perf_counter``, the clock the device
-trace is moved onto), ``rid``, ``tid`` and ``cause``.  A run carries them
-as ``run.spans``, and each request of the window its token stamps
-(``Request.t_tokens``) as ``tokens``.  A run without them (the engine
-built without spans, or a harness that does not carry them) gives None
-here, and its metrics are left out.
+trace is moved onto), ``rid``, ``tid`` and ``cause``.  A traced run
+carries them as ``run.spans``, and each request of the window its token
+stamps (``Request.t_tokens``) as ``tokens``.  A run without them (an
+untraced run, or a program that makes none) gives None here, and its
+metrics are left out.
 """
 from __future__ import annotations
 
@@ -28,7 +28,7 @@ DECODE_PROGRAM = "jit_serve_decode"
 def in_window(run, *names: str) -> Optional[list]:
     """The spans named ``names`` that start in the window; None where the
     run carries no spans."""
-    spans = getattr(run, "spans", None)
+    spans = run.spans
     if spans is None:
         return None
     lo, hi = run.window
@@ -57,16 +57,15 @@ def per_task_us(run, names: tuple) -> Optional[float]:
 def inter_token_s(run) -> Optional[list[float]]:
     """The gaps between consecutive token stamps of every request of the
     window; an unfinished request adds one missing gap (inf).  None where
-    the requests carry no stamps."""
+    the requests carry no stamps (a finished request without any)."""
     out: list[float] = []
     for r in run.requests:
-        toks = getattr(r, "tokens", None)
-        if toks is None:
-            return None
-        if r.finished:
-            out.extend(np.diff(toks).tolist())
-        else:
+        if not r.finished:
             out.append(float("inf"))
+        elif not r.tokens:
+            return None
+        else:
+            out.extend(np.diff(r.tokens).tolist())
     return out
 
 
@@ -84,7 +83,7 @@ def idle_in_launch_pct(run) -> Optional[float]:
     """Share of the window in which the device is idle while the host is
     inside a step's launch: idle gaps whose midpoint lies in a
     ``prefill.launch`` or ``decode.launch`` span, in %."""
-    spans = getattr(run, "spans", None)
+    spans = run.spans
     ops = tr.ops(run.events) if run.events is not None else []
     if spans is None or not ops:
         return None

@@ -51,6 +51,31 @@ def test_traced_run_reports_per_layer_metrics(tmp_path):
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
+@pytest.mark.parametrize("trace", [False, True],
+                         ids=["untraced", "traced"])
+def test_run_carries_spans_stamps_and_counters(trace, tmp_path):
+    """Every run carries each request's token stamps and the engine's
+    counters; only a traced run turns the engine's spans on and carries
+    them."""
+    result, info = _run(trace=trace, tmp_path=tmp_path)
+    assert result["correct"], result["checks"]
+    data = info["data"]
+    done = [r for r in data.requests if r.finished]
+    assert done
+    for r in done:
+        assert len(r.tokens) == r.n_out
+        assert r.tokens[0] == r.first and r.tokens[-1] == r.done
+        assert list(r.tokens) == sorted(r.tokens)
+    c = data.counters
+    assert c["decode_steps"] >= sum(r.n_out - 1 for r in done) > 0
+    assert 0 <= c["decode_in_place"] <= c["decode_steps"]
+    if trace:
+        names = {s.name for s in data.spans}
+        assert {"decode.launch", "decode.token_wait", "commit"} <= names
+    else:
+        assert data.spans is None
+
+
 def _break_decode(monkeypatch, how):
     from repro.serve import engine as eng
     real = eng.ServingEngine._run_decode

@@ -1,6 +1,6 @@
-"""The readers of the program's host spans and token stamps, and of the
-named device programs: on hand-made runs, on a tiny CPU run with the
-engine's spans on, and on a slice recorded on a TPU v5e."""
+"""The readers of the program's host spans, token stamps and counters,
+and of the named device programs: on hand-made runs, on a tiny traced CPU
+run (the engine's spans on), and on a slice recorded on a TPU v5e."""
 import time
 
 import pytest
@@ -9,7 +9,6 @@ from chipbench import cell
 from chipbench import trace as tr
 from chipbench.spec import reader
 from chipbench.tests.tiny import tiny_spec
-from chipbench.tools import span_run
 from repro.core import Span
 
 E = tr.Event
@@ -19,18 +18,15 @@ SPAN_READERS = ["itl_ms.p95", "decode_launch_ms.open",
 DEVICE_READERS = ["decode_step_device_ms.open", "programs_per_token.open"]
 
 
-def _req(first, done, n_out, tokens=None):
-    r = cell.Req(first, first, first, done, 16, n_out, n_out)
-    return r if tokens is None else span_run.Stamped(r, tokens)
+def _req(first, done, n_out, tokens=()):
+    return cell.Req(first, first, first, done, 16, n_out, n_out, tokens)
 
 
 def _run(requests=(), events=None, spans=None, tokens=0,
-         window=(0.0, 1.0)):
-    run = cell.RunData(None, window[1] - window[0], window, 0.0,
-                       list(requests), [], 0, tokens, events, None)
-    if spans is not None:
-        run.spans = spans
-    return run
+         window=(0.0, 1.0), counters=None):
+    return cell.RunData(None, window[1] - window[0], window, 0.0,
+                        list(requests), [], 0, tokens, events, None, spans,
+                        counters)
 
 
 def _spans():
@@ -98,10 +94,19 @@ def test_idle_in_launch():
     assert reader("device_idle_launch.open")(run) == pytest.approx(20.0)
 
 
+def test_decode_in_place_share_reads_the_counters():
+    share = reader("decode_in_place_share.closed")
+    counted = {"decode_steps": 40, "decode_in_place": 30, "n_cores": 4}
+    assert share(_run(counters=counted)) == pytest.approx(0.75)
+    assert share(_run(counters={"decode_steps": 0,
+                                "decode_in_place": 0})) is None
+    assert share(_run()) is None
+
+
 @pytest.mark.parametrize("name", SPAN_READERS + DEVICE_READERS)
 def test_nothing_to_read_gives_nothing(name):
-    """A run of a program without spans, stamps or named programs, as
-    the harness carries it today, reads nothing."""
+    """A run without spans, stamps or named programs (an untraced run,
+    or a program that makes none) reads nothing."""
     plain = _run([_req(0.1, 0.4, 4)], tokens=4, events=[
         E("XLA Ops", "fusion.1", 0.0, 0.1, "")])
     untraced = _run([_req(0.1, 0.4, 4)], spans=[], tokens=4)
@@ -111,14 +116,16 @@ def test_nothing_to_read_gives_nothing(name):
 
 def test_traced_cpu_run_reports_span_metrics(tmp_path):
     spec = tiny_spec("dense", "open")
-    result, info = span_run.run_spanned(
+    result, info = cell.run(
         spec, 2**31 + 11, 2.0, True, t_proc0=time.perf_counter(),
         trace_dir=str(tmp_path), require_chip=False)
     assert result["correct"], result["checks"]
     got = result["metrics"]
-    five = [n for n, _ in span_run.METRICS["open"]]
-    assert len(five) == 5 and set(five) <= set(got)
+    five = SPAN_READERS[:5]
+    assert set(five) <= {m["name"] for m in spec.per_layer}
+    assert set(five) <= set(got)
     assert all(got[n]["value"] > 0 for n in five)
+    assert got["decode_in_place_share.open"]["value"] == 1.0
     data = info["data"]
     assert all(len(r.tokens) == r.n_out for r in data.requests)
     assert {s.name for s in data.spans} >= {
@@ -127,8 +134,8 @@ def test_traced_cpu_run_reports_span_metrics(tmp_path):
 
 
 def test_recorded_v5e_slice_with_spans():
-    """A slice recorded on a TPU v5e (``tools/span_run.py --trace 1
-    --out``, cell stablelm-3b.chat_poisson): the first 150 ms of a traced
+    """A slice recorded on a TPU v5e (``tools/span_run.py --out``, cell
+    stablelm-3b.chat_poisson): the first 150 ms of a traced
     window, with the named programs, the spans and the token stamps."""
     import gzip
     import json
